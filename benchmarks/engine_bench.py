@@ -561,7 +561,7 @@ def bench_paged_prefix(model, params, cfg, smoke):
 
 
 def _setup(cfg, max_new):
-    model = Model(cfg, use_kernels=False)
+    model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     reqs = _mk_requests(cfg, SLOTS, max_new)
     peng = PrefillEngine(model, params, min_bucket=32)

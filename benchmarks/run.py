@@ -16,6 +16,7 @@ from benchmarks import (common, engine_bench, fig5_gridsearch, kernel_bench,
                         scenario_grid, serve_live, sim_ttft,
                         table3_kv_throughput, table5_profile,
                         table6_deployment)
+from repro.compile_cache import enable_compile_cache
 
 MODULES = {
     "table3": table3_kv_throughput,    # Table 3 / Figure 2 (Φ_kv by model)
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="short horizons; finish the harness in seconds")
     args = ap.parse_args()
+    enable_compile_cache()
     names = [args.only] if args.only else list(MODULES)
     print("name,us_per_call,derived")
     failed = []

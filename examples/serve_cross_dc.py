@@ -18,7 +18,7 @@ from repro.serving import CrossDCDeployment, DeploymentConfig, Request
 ARCH = "kimi-linear-1t"          # the paper's case-study family
 
 cfg = get_smoke_config(ARCH)
-model = Model(cfg, use_kernels=False)
+model = Model(cfg)
 params = model.init(jax.random.PRNGKey(0))
 dep = CrossDCDeployment(
     model, params,

@@ -23,22 +23,7 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
-
-
-def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
-    """Version-compat constructor for ``jax.sharding.AbstractMesh``.
-
-    jax <= 0.4.x takes a single ``shape_tuple`` of (name, size) pairs;
-    jax >= 0.5 takes ``(axis_sizes, axis_names)``.  Accepts either call
-    style's data and dispatches to whichever the installed jax supports:
-
-        abstract_mesh((16, 16), ("data", "model"))
-    """
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def _divisible(dim: int, mesh: Mesh, axis: str) -> bool:

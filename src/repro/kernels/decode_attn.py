@@ -2,8 +2,10 @@
 
 Decode is memory-bandwidth bound: the kernel streams (block_k x D) KV tiles
 HBM->VMEM once, with running-softmax statistics in VMEM scratch. Per-request
-cache lengths arrive in SMEM ((1,1) int32 blocks); sliding-window archs mask
-keys below ``length - window`` so SWA decode touches O(window) bytes.
+cache lengths ride in as a scalar-prefetch operand (SMEM, read by row index
+in the body: a (1, 1) block of a (B, 1) array breaks the TPU's (8, 128)
+block rule). Sliding-window archs mask keys below ``length - window`` so
+SWA decode touches O(window) bytes.
 """
 from __future__ import annotations
 
@@ -18,7 +20,8 @@ NEG_INF = float("-inf")
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                   *, scale, window, block_k, num_kv_blocks):
+                   *, scale, window, block_k, num_kv_blocks, num_q_heads):
+    h = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -27,7 +30,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[h // num_q_heads]
     k_start = j * block_k
     live = k_start < length
     if window > 0:
@@ -84,30 +87,33 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
     qr = q.reshape(B * Hq, 1, D)
     kr = kp.reshape(B * Hkv, Sp, D)
     vr = vp.reshape(B * Hkv, Sp, Dv)
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
+    lens = lengths.astype(jnp.int32).reshape(B)
 
-    def kv_map(h, j):
+    def kv_map(h, j, lens_ref):
         return ((h // Hq) * Hkv + (h % Hq) // group, j, 0)
 
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
-                               block_k=block_k, num_kv_blocks=nk)
-    out = pl.pallas_call(
-        kernel,
+                               block_k=block_k, num_kv_blocks=nk,
+                               num_q_heads=Hq)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B * Hq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda h, j: (h // Hq, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, D), lambda h, j: (h, 0, 0)),
+            pl.BlockSpec((1, 1, D), lambda h, j, lens_ref: (h, 0, 0)),
             pl.BlockSpec((1, block_k, D), kv_map),
             pl.BlockSpec((1, block_k, Dv), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, Dv), lambda h, j: (h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hq, 1, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, Dv), lambda h, j, lens_ref: (h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, Dv), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * Hq, 1, Dv), q.dtype),
         interpret=interpret,
     )(lens, qr, kr, vr)
     return out.reshape(B, Hq, Dv)

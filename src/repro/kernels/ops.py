@@ -1,15 +1,15 @@
 """Public kernel ops: jit-friendly dispatch wrappers.
 
 Each op:
-  * runs the Pallas kernel on TPU, or in ``interpret=True`` mode on CPU
-    (the kernel body executes in Python — bit-accurate vs the TPU lowering
-    semantics, used by the test suite);
+  * runs its compiled Pallas kernel on TPU — there is no other TPU path
+    unless the call site passes ``use_kernel=False``; on CPU models lower
+    the jnp paths, and the kernel tests run the kernel body in
+    ``interpret=True`` mode (see ``_on_cpu_lowering``);
   * is differentiable via ``jax.custom_vjp`` whose backward pass is the VJP
     of the pure-jnp oracle with recomputation (flash-attention-style: store
     only the inputs, recompute the forward in the backward). Gradients are
     therefore oracle-exact while the forward stays on the kernel.
-  * can be forced onto the oracle with ``use_kernel=False`` (or globally via
-    ``repro.kernels.ops.FORCE_REF`` for debugging).
+  * can be forced onto the oracle with ``use_kernel=False``.
 """
 from __future__ import annotations
 
@@ -27,8 +27,6 @@ from repro.kernels import gla as _gla
 from repro.kernels import quantize as _quant
 from repro.kernels import ref
 
-FORCE_REF = False
-
 # lowerable memory-efficient paths (used when the TPU kernel is unavailable
 # -- CPU tests and the dry-run -- and as the kernels' backward recompute)
 from repro.models import chunked_attention as chk
@@ -39,12 +37,6 @@ SMALL_SEQ = 1024
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
-
-
-def _use_kernel(flag):
-    if FORCE_REF:
-        return False
-    return flag
 
 
 # ---------------------------------------------------------------------------
@@ -103,7 +95,7 @@ def _attention_jnp(q, k, v, *, causal=True, window=0, scale=None,
 def attention(q, k, v, *, causal=True, window=0, scale=None, q_offset=0,
               block_q=128, block_k=128, use_kernel=True):
     """Full attention (GQA/MQA/MHA/SWA). q:(B,Hq,S,D) k,v:(B,Hkv,S,D)."""
-    if not _use_kernel(use_kernel) or _on_cpu_lowering(k.shape[2]):
+    if not use_kernel or _on_cpu_lowering(k.shape[2]):
         return _attention_jnp(q, k, v, causal=causal, window=window,
                               scale=scale, q_offset=q_offset)
     op = _flash_vjp(causal, window, scale, q_offset, block_q, block_k,
@@ -176,12 +168,12 @@ def gla(q, k, v, log_a, initial_state=None, *, lengths=None, chunk=64,
     if initial_state is None:
         initial_state = jnp.zeros((B, H, dk, dv), jnp.float32)
     if lengths is None:
-        if not _use_kernel(use_kernel) or _on_cpu_lowering(S):
+        if not use_kernel or _on_cpu_lowering(S):
             return chk.gla_chunked_jnp(q, k, v, log_a, initial_state,
                                        chunk=chunk)
         return _gla_vjp(chunk, _on_cpu())(q, k, v, log_a, initial_state)
     lengths = jnp.asarray(lengths, jnp.int32)
-    if not _use_kernel(use_kernel) or _on_cpu_lowering(S):
+    if not use_kernel or _on_cpu_lowering(S):
         log_a, k = _mask_padded(lengths, S, log_a, k)
         return chk.gla_chunked_jnp(q, k, v, log_a, initial_state, chunk=chunk)
     interpret = _on_cpu()
@@ -244,13 +236,13 @@ def delta(q, k, v, log_a, beta, initial_state=None, *, lengths=None,
     if initial_state is None:
         initial_state = jnp.zeros((B, H, dk, dv), jnp.float32)
     if lengths is None:
-        if not _use_kernel(use_kernel) or _on_cpu_lowering(S):
+        if not use_kernel or _on_cpu_lowering(S):
             return chk.delta_chunked_jnp(q, k, v, log_a, beta, initial_state,
                                          chunk=chunk)
         return _delta_vjp(chunk, _on_cpu())(q, k, v, log_a, beta,
                                             initial_state)
     lengths = jnp.asarray(lengths, jnp.int32)
-    if not _use_kernel(use_kernel) or _on_cpu_lowering(S):
+    if not use_kernel or _on_cpu_lowering(S):
         log_a, k, beta = _mask_padded(lengths, S, log_a, k, beta)
         return chk.delta_chunked_jnp(q, k, v, log_a, beta, initial_state,
                                      chunk=chunk)
@@ -285,7 +277,7 @@ def delta(q, k, v, log_a, beta, initial_state=None, *, lengths=None,
 
 def decode_attention(q, k_cache, v_cache, lengths, *, window=0, scale=None,
                      block_k=512, use_kernel=True):
-    if not _use_kernel(use_kernel) or _on_cpu_lowering(k_cache.shape[2]):
+    if not use_kernel or _on_cpu_lowering(k_cache.shape[2]):
         return ref.decode_attention_ref(q, k_cache, v_cache, lengths,
                                         window=window, scale=scale)
     return _decode.decode_attention(q, k_cache, v_cache, lengths,
@@ -303,7 +295,7 @@ def verify_attention(q, k_cache, v_cache, lengths, *, scale=None,
     (the hot-path win: Q× fewer attention ops per layer); the kernel path
     unrolls Q calls of the flash decode kernel so accelerator numerics stay
     bit-identical to the plain one-token decode dispatch."""
-    if not _use_kernel(use_kernel) or _on_cpu_lowering(k_cache.shape[2]):
+    if not use_kernel or _on_cpu_lowering(k_cache.shape[2]):
         return ref.verify_attention_ref(q, k_cache, v_cache, lengths,
                                         scale=scale)
     S = k_cache.shape[2]
@@ -319,7 +311,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *, window=0,
     """Block-table flash-decode: KV gathered from a shared page pool.
 
     q: (B, Hq, D); pages: (Hkv, P, T, D); tables: (B, N) int32."""
-    if not _use_kernel(use_kernel) or _on_cpu_lowering(
+    if not use_kernel or _on_cpu_lowering(
             tables.shape[1] * k_pages.shape[2]):
         return ref.paged_decode_attention_ref(q, k_pages, v_pages, tables,
                                               lengths, window=window,
@@ -338,7 +330,7 @@ def paged_prefill_attention(q, k_pages, v_pages, tables, k_suf, v_suf, *,
     k_suf/v_suf: (B, Hkv, Ssuf, D) dense suffix keys whose last C rows are
     the chunk's own (causally masked)."""
     total = tables.shape[1] * k_pages.shape[2] + k_suf.shape[2]
-    if not _use_kernel(use_kernel) or _on_cpu_lowering(total):
+    if not use_kernel or _on_cpu_lowering(total):
         return ref.paged_prefill_attention_ref(q, k_pages, v_pages, tables,
                                                k_suf, v_suf, scale=scale)
     return _paged_prefill.paged_prefill_attention(
@@ -351,7 +343,7 @@ def quantize_wire(x, *, use_kernel=True):
 
     Returns (q: int8, scale: float32 scalar), byte-identical between the
     fused Pallas pass and the jnp ref (same max/round/clip chain)."""
-    if not _use_kernel(use_kernel) or _on_cpu_lowering(x.size):
+    if not use_kernel or _on_cpu_lowering(x.size):
         return ref.quantize_int8_ref(x)
     return _quant.quantize_int8_fused(x, interpret=_on_cpu())
 

@@ -31,19 +31,24 @@ _LANE = 128
 
 def _quantize_kernel(x_ref, q_ref, scale_ref, amax, *, num_tiles):
     """Grid (2, num_tiles): phase 0 reduces |x| into ``amax`` scratch,
-    phase 1 encodes every tile against the finished per-tensor scale."""
+    phase 1 encodes every tile against the finished per-tensor scale.
+
+    The absmax and scale stay (1, 1) vectors end to end: the TPU cannot
+    store a scalar to VMEM."""
     phase = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when((phase == 0) & (j == 0))
     def _zero():
-        amax[0, 0] = jnp.float32(0.0)
+        amax[...] = jnp.zeros_like(amax)
 
     x = x_ref[...].astype(jnp.float32)
 
     @pl.when(phase == 0)
     def _reduce():
-        amax[0, 0] = jnp.maximum(amax[0, 0], jnp.max(jnp.abs(x)))
+        tile = jnp.max(jnp.max(jnp.abs(x), axis=0, keepdims=True), axis=1,
+                       keepdims=True)                   # (1, 1)
+        amax[...] = jnp.maximum(amax[...], tile)
 
     @pl.when(phase == 1)
     def _encode():
@@ -51,13 +56,13 @@ def _quantize_kernel(x_ref, q_ref, scale_ref, amax, *, num_tiles):
         # constant divisions to reciprocal multiplies under jit, so an
         # explicit multiply is the only form that is bit-stable between
         # this (jitted) kernel and the eager jnp ref
-        scale = jnp.maximum(amax[0, 0], 1e-30) * (1.0 / 127.0)
+        scale = jnp.maximum(amax[...], 1e-30) * (1.0 / 127.0)
         q_ref[...] = jnp.clip(jnp.round(x / scale), -127, 127
                               ).astype(jnp.int8)
 
         @pl.when(j == 0)
         def _emit_scale():
-            scale_ref[0, 0] = scale
+            scale_ref[...] = scale
 
 
 def quantize_int8_fused(x, *, interpret: bool = False):
@@ -80,7 +85,9 @@ def quantize_int8_fused(x, *, interpret: bool = False):
         grid=(2, tiles),
         in_specs=[pl.BlockSpec((_TILE_ROWS, _LANE), lambda p, j: (j, 0))],
         out_specs=[
-            pl.BlockSpec((_TILE_ROWS, _LANE), lambda p, j: (j, 0)),
+            # phase 0 parks on block 0 and writes nothing, so no garbage
+            # tile is written back before phase 1 encodes it
+            pl.BlockSpec((_TILE_ROWS, _LANE), lambda p, j: (j * p, 0)),
             pl.BlockSpec((1, 1), lambda p, j: (0, 0)),
         ],
         out_shape=[

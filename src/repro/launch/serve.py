@@ -254,7 +254,7 @@ def run_serve(args) -> dict:
         spec_k=args.spec_k, spec_ngram=args.spec_ngram,
         tbt_slo_s=args.tbt_slo,
         calibration=args.calibration)
-    model = Model(cfg, use_kernels=False)
+    model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     dep = CrossDCDeployment(model, params, dep_cfg)
 
@@ -272,7 +272,10 @@ def run_serve(args) -> dict:
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+
     args = build_parser().parse_args()
+    enable_compile_cache()
     report = run_serve(args)
     for r in report.pop("_requests"):
         print(f"req {r.rid}: len={len(r.tokens):4d} home={r.home:5s} "

@@ -1,7 +1,8 @@
 """Block pool + hybrid prefix cache: unit + hypothesis property tests."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.blockpool import PREFIX, TRANSFER, BlockPool
 from repro.core.prefix_cache import HybridPrefixCache, token_block_hashes

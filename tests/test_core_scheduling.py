@@ -3,7 +3,8 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (PD, PRFAAS, Autoscaler, Router, RouterConfig,
                         StageTelemetry, SystemConfig, ThroughputModel,

@@ -4,14 +4,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.analysis.costfit import basis_row
 from repro.analysis.roofline import collective_bytes
-from repro.distributed.sharding import _with_fsdp, abstract_mesh, param_pspec
+from repro.distributed.sharding import _with_fsdp, param_pspec
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
-MESH3 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestParamShardingRules:

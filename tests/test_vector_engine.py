@@ -5,7 +5,8 @@ solver, epoch-grid snapping, and the trace-driven workload layer."""
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.core import (PRFAAS, PrfaasSimulator, SimConfig, ThroughputModel,
                         Trace, Workload, conversation_trace, diurnal_trace,
                         flash_crowd_trace, paper_h20_profile,
