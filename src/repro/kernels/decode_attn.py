@@ -112,6 +112,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
     )
     out = pl.pallas_call(
         kernel,
+        name="decode_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, Dv), q.dtype),
         interpret=interpret,

@@ -165,6 +165,7 @@ def _delta_call(q, k, v, log_a, beta, lengths, initial_state, chunk,
     )
     o, sT = pl.pallas_call(
         kernel,
+        name="delta",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sp, dv), q.dtype),

@@ -118,6 +118,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
     out = pl.pallas_call(
         kernel,
+        name="flash_attn",
         grid=(B * Hq, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_map),

@@ -143,6 +143,7 @@ def _gla_call(q, k, v, log_a, lengths, initial_state, chunk, interpret):
     )
     o, sT = pl.pallas_call(
         kernel,
+        name="gla",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sp, dv), q.dtype),

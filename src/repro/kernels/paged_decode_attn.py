@@ -111,6 +111,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, Dv), q.dtype),
         interpret=interpret,

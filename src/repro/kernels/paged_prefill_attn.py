@@ -128,6 +128,7 @@ def paged_prefill_attention(q, k_pages, v_pages, tables, k_suf, v_suf, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_prefill_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, C, Dv), q.dtype),
         interpret=interpret,

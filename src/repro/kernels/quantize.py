@@ -82,6 +82,7 @@ def quantize_int8_fused(x, *, interpret: bool = False):
     kernel = functools.partial(_quantize_kernel, num_tiles=tiles)
     q, scale = pl.pallas_call(
         kernel,
+        name="quantize",
         grid=(2, tiles),
         in_specs=[pl.BlockSpec((_TILE_ROWS, _LANE), lambda p, j: (j, 0))],
         out_specs=[

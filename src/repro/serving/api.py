@@ -35,6 +35,15 @@ class Request:
     kv_bytes_raw: int = 0              # raw cache bytes before compression
     cross_kv_bytes: float = 0.0        # cross-cluster cached-prefix copy
     ttft_s: float = 0.0
+    # wall stamps on the span clock (``time.perf_counter()``, see
+    # ``serving.spans``), each set once where its event happens: routed into
+    # a region's queue, prefill unit formed, first token on the host (unit
+    # done: trimmed and through the wire), placed into a decode slot, retired
+    t_submit: Optional[float] = None
+    t_unit_start: Optional[float] = None
+    t_first: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_finish: Optional[float] = None
     # the core.router.RoutingDecision that placed this request (set by
     # CrossDCDeployment._route; None until routed)
     decision: Optional[object] = None

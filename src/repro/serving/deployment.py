@@ -88,6 +88,7 @@ from repro.models.kvcache import (cache_num_bytes, dequantize_cache_from_wire,
 from repro.serving.api import PagePin, Request, Response
 from repro.serving.engine import (DecodeEngine, PrefillEngine,
                                   RegionScheduler, trim_request_cache)
+from repro.serving.spans import span
 
 
 @dataclass
@@ -265,33 +266,34 @@ class CrossDCDeployment:
 
     # ------------------------------------------------------------- routing
     def _route(self, req: Request) -> RoutingDecision:
-        home = req.home or self.pd_names[0]
-        if home not in self.pd_names:
-            raise ValueError(f"unknown home region {home!r}; "
-                             f"expected one of {self.pd_names}")
-        req.home = home
-        toks = list(map(int, req.tokens))
-        matches = self.kv.match_all(
-            toks, names=[n for n in self.caches
-                         if self.topology.cache_reachable(home, n,
-                                                          hub=PRFAAS)])
-        decision = self.router.route(len(toks), matches,
-                                     self.topology.pair_signal(PRFAAS, home),
-                                     home=home)
-        req.decision = decision
-        req.route = decision.target
-        req.cached_tokens = decision.cached_tokens
-        if self.cfg.paged_kv and decision.target == home:
-            # local prefill on a paged region: pin the device-resident
-            # prefix pages (ref-counts transfer to the engine at admission)
-            # so only the uncached suffix is computed.  An offloaded
-            # prefill cannot use home device pages — it ships the full
-            # cache as before.
-            c, ids, snap = self.caches[home].match_resume(toks)
-            if c:
-                self.decoders[home].pool.retain(ids)
-                req.device_pin = PagePin(c, ids, snap)
-        return decision
+        with span("prfaas.route"):
+            home = req.home or self.pd_names[0]
+            if home not in self.pd_names:
+                raise ValueError(f"unknown home region {home!r}; "
+                                 f"expected one of {self.pd_names}")
+            req.home = home
+            toks = list(map(int, req.tokens))
+            matches = self.kv.match_all(
+                toks, names=[n for n in self.caches
+                             if self.topology.cache_reachable(home, n,
+                                                              hub=PRFAAS)])
+            decision = self.router.route(
+                len(toks), matches, self.topology.pair_signal(PRFAAS, home),
+                home=home)
+            req.decision = decision
+            req.route = decision.target
+            req.cached_tokens = decision.cached_tokens
+            if self.cfg.paged_kv and decision.target == home:
+                # local prefill on a paged region: pin the device-resident
+                # prefix pages (ref-counts transfer to the engine at
+                # admission) so only the uncached suffix is computed.  An
+                # offloaded prefill cannot use home device pages — it ships
+                # the full cache as before.
+                c, ids, snap = self.caches[home].match_resume(toks)
+                if c:
+                    self.decoders[home].pool.retain(ids)
+                    req.device_pin = PagePin(c, ids, snap)
+            return decision
 
     # ------------------------------------------------------------ lifecycle
     def _unit_done(self, engine: PrefillEngine, rs: List[Request], lengths,
@@ -301,92 +303,103 @@ class CrossDCDeployment:
         true lengths, quantize + submit wire flows, insert prefix-cache
         entries, compute transfer exposure and TTFT — exactly the
         accounting the old per-cluster batch loop did, at unit granularity.
-        Returns the decode admit entries for the scheduler's ready queue."""
-        self.topology.advance(self.virtual_now)      # sync link clocks
-        flows: Dict[int, list] = {}
-        entries = []
-        for i, r in enumerate(rs):
-            cluster = r.decision.target
-            r.prefill_s = wall
-            # trim to the request's true length: bucket padding must not
-            # inflate wire bytes (or corrupt SWA ring placement)
-            payload = trim_request_cache(caches, i, len(r.tokens))
-            r.kv_bytes_raw = cache_num_bytes(payload)
-            r.transfer_s = 0.0
-            fl = []
-            if cluster == PRFAAS:
-                if self.cfg.wire_compression:
-                    # the quantized pytree IS what crosses the link: bytes
-                    # come from the quantized leaves, and the cache is
-                    # dequantized before decode admission
-                    payload, nbytes = quantize_cache_for_wire(payload)
-                    self._wire_raw += r.kv_bytes_raw
-                    self._wire_quant += nbytes
+        Returns the decode admit entries for the scheduler's ready queue.
+        An offloaded request's quantize, link submit and dequantize run in
+        one ``prfaas.wire`` span."""
+        with span("prfaas.unit_done"):
+            self.topology.advance(self.virtual_now)      # sync link clocks
+            flows: Dict[int, list] = {}
+            entries = []
+            for i, r in enumerate(rs):
+                cluster = r.decision.target
+                r.prefill_s = wall
+                # trim to the request's true length: bucket padding must not
+                # inflate wire bytes (or corrupt SWA ring placement)
+                payload = trim_request_cache(caches, i, len(r.tokens))
+                r.kv_bytes_raw = cache_num_bytes(payload)
+                r.transfer_s = 0.0
+                fl = []
+                if cluster == PRFAAS:
+                    with span("prfaas.wire", rid=r.rid) as sp:
+                        payload, flow = self._wire(r, payload, wall)
+                        sp.attrs["bytes"] = int(r.kv_bytes)
+                    fl.append(("kv", PRFAAS, r.home, flow))
                 else:
-                    nbytes = r.kv_bytes_raw
-                r.kv_bytes = nbytes
-                # layer-wise pipelined: KV becomes wire-eligible as prefill
-                # computes (linear ramp over the prefill); unpipelined: the
-                # flow only starts once prefill ends.  Either way the
-                # unit's flows contend on the exact fair-share pair link
-                # solver.
-                start = (self.virtual_now if self.cfg.layerwise_pipeline
-                         else self.virtual_now + wall)
-                fl.append(("kv", PRFAAS, r.home, self.topology.submit(
-                    PRFAAS, r.home, max(float(nbytes), 1.0), start,
-                    ramp_end=self.virtual_now + wall)))
-            else:
-                r.kv_bytes = r.kv_bytes_raw          # intra-cluster RDMA
-            d = r.decision
-            if d.cross_cache_transfer and d.cached_tokens:
-                # cached prefix lives in another cluster: the copy is
-                # already materialized (eager flow), charged to the
-                # owner<->target pair link, compressed like the rest of the
-                # wire traffic
-                nb = float(kv_bytes(self.model.cfg, d.cached_tokens))
-                if self.cfg.wire_compression:
-                    nb /= self.measured_compression()
-                nb = max(nb, 1.0)
-                r.cross_kv_bytes = nb
-                fl.append(("copy", d.cache_cluster, d.target,
-                           self.topology.submit(
-                               d.cache_cluster, d.target, nb,
-                               self.virtual_now,
-                               ramp_end=self.virtual_now)))
-            flows[r.rid] = fl
-            if not (self.cfg.paged_kv and cluster != PRFAAS):
-                # paged regions register their device pages at ADMISSION
-                # (insert_device): inserting metadata blocks here would bind
-                # prefix hashes to pageless entries that match_resume would
-                # hand back as if they held KV
-                self.kv.record_prefill(cluster, list(map(int, r.tokens)))
-            if (self.cfg.wire_compression and cluster == PRFAAS
-                    and not getattr(self.decoders[r.home],
-                                    "wire_admission", False)):
-                # dense admission needs the dense pytree back; paged homes
-                # with wire admission dequantize inside the page scatter
-                payload = dequantize_cache_from_wire(payload)
-            entries.append((r, int(first[i]), payload, len(r.tokens)))
-        if any(flows.values()):
-            self.topology.run_until_idle()
-        for r in rs:
-            exposure = 0.0
-            for kind, a, b, f in flows.get(r.rid, ()):
-                tail = 0.0
-                if kind == "kv":
-                    # the pipelined prefill KV's last layer can never
-                    # overlap its own compute (eager "copy" flows are
-                    # already materialized: no serial tail)
-                    floor = 1.0 / max(1, self.model.cfg.n_layers)
-                    tail = f.total_bytes * floor \
-                        / self.topology.link(a, b).current_capacity()
-                exposed = f.done_time - (self.virtual_now + wall)
-                exposure = max(exposure, exposed, tail)
-            if flows.get(r.rid):
-                r.transfer_s = max(exposure, 0.0)
-            r.ttft_s = r.prefill_s + r.transfer_s
-        self.virtual_now += wall
-        return entries
+                    r.kv_bytes = r.kv_bytes_raw          # intra-cluster RDMA
+                d = r.decision
+                if d.cross_cache_transfer and d.cached_tokens:
+                    # cached prefix lives in another cluster: the copy is
+                    # already materialized (eager flow), charged to the
+                    # owner<->target pair link, compressed like the rest of
+                    # the wire traffic
+                    nb = float(kv_bytes(self.model.cfg, d.cached_tokens))
+                    if self.cfg.wire_compression:
+                        nb /= self.measured_compression()
+                    nb = max(nb, 1.0)
+                    r.cross_kv_bytes = nb
+                    fl.append(("copy", d.cache_cluster, d.target,
+                               self.topology.submit(
+                                   d.cache_cluster, d.target, nb,
+                                   self.virtual_now,
+                                   ramp_end=self.virtual_now)))
+                flows[r.rid] = fl
+                if not (self.cfg.paged_kv and cluster != PRFAAS):
+                    # paged regions register their device pages at
+                    # ADMISSION (insert_device): inserting metadata blocks
+                    # here would bind prefix hashes to pageless entries that
+                    # match_resume would hand back as if they held KV
+                    self.kv.record_prefill(cluster, list(map(int, r.tokens)))
+                entries.append((r, int(first[i]), payload, len(r.tokens)))
+            if any(flows.values()):
+                self.topology.run_until_idle()
+            for r in rs:
+                exposure = 0.0
+                for kind, a, b, f in flows.get(r.rid, ()):
+                    tail = 0.0
+                    if kind == "kv":
+                        # the pipelined prefill KV's last layer can never
+                        # overlap its own compute (eager "copy" flows are
+                        # already materialized: no serial tail)
+                        floor = 1.0 / max(1, self.model.cfg.n_layers)
+                        tail = f.total_bytes * floor \
+                            / self.topology.link(a, b).current_capacity()
+                    exposed = f.done_time - (self.virtual_now + wall)
+                    exposure = max(exposure, exposed, tail)
+                if flows.get(r.rid):
+                    r.transfer_s = max(exposure, 0.0)
+                r.ttft_s = r.prefill_s + r.transfer_s
+            self.virtual_now += wall
+            return entries
+
+    def _wire(self, r: Request, payload, wall: float):
+        """Ship an offloaded request's trimmed cache over its home's link:
+        quantize (int8 wire), submit the flow, and dequantize again where
+        the home's admission needs the dense pytree.  Returns (the payload
+        to admit, the flow)."""
+        if self.cfg.wire_compression:
+            # the quantized pytree IS what crosses the link: bytes come from
+            # the quantized leaves, and the cache is dequantized before
+            # decode admission
+            payload, nbytes = quantize_cache_for_wire(payload)
+            self._wire_raw += r.kv_bytes_raw
+            self._wire_quant += nbytes
+        else:
+            nbytes = r.kv_bytes_raw
+        r.kv_bytes = nbytes
+        # layer-wise pipelined: KV becomes wire-eligible as prefill computes
+        # (linear ramp over the prefill); unpipelined: the flow only starts
+        # once prefill ends.  Either way the unit's flows contend on the
+        # exact fair-share pair link solver.
+        start = (self.virtual_now if self.cfg.layerwise_pipeline
+                 else self.virtual_now + wall)
+        flow = self.topology.submit(PRFAAS, r.home, max(float(nbytes), 1.0),
+                                    start, ramp_end=self.virtual_now + wall)
+        if self.cfg.wire_compression and not getattr(
+                self.decoders[r.home], "wire_admission", False):
+            # dense admission needs the dense pytree back; paged homes with
+            # wire admission dequantize inside the page scatter
+            payload = dequantize_cache_from_wire(payload)
+        return payload, flow
 
     def submit_batch(self, reqs: List[Request]) -> Dict[int, Response]:
         """Serve a batch of requests end-to-end; returns responses.
@@ -453,9 +466,20 @@ class CrossDCDeployment:
                                if slo_s > 0 else 1.0),
         }
 
+    @staticmethod
+    def _latency_means(rs: List[Request]) -> dict:
+        """Mean wall TTFT (submit -> first token on the host, both stamped
+        on the span clock) and, apart from it, the mean virtual-clock link
+        exposure of the same requests."""
+        ttft = [r.t_first - r.t_submit for r in rs
+                if r.t_first is not None and r.t_submit is not None]
+        return {"ttft_mean_s": float(np.mean(ttft)) if ttft else 0.0,
+                "link_exposed_s_mean": (float(np.mean([r.transfer_s
+                                                       for r in rs]))
+                                        if rs else 0.0)}
+
     def metrics(self) -> dict:
         done = self.completed
-        ttft = [r.ttft_s for r in done]
         per_region = {}
         for name in self.pd_names:
             rs = [r for r in done if r.home == name]
@@ -463,8 +487,7 @@ class CrossDCDeployment:
             per_region[name] = {
                 "requests": len(rs),
                 "offloaded": sum(1 for r in rs if r.route == PRFAAS),
-                "ttft_mean_s": float(np.mean([r.ttft_s for r in rs]))
-                if rs else 0.0,
+                **self._latency_means(rs),
                 "threshold": self.router.threshold_for(name),
                 "cache_hit_rate": self.caches[name].hit_rate(),
                 "truncations": self.decoders[name].truncations,
@@ -496,7 +519,7 @@ class CrossDCDeployment:
         return {
             "requests": len(done),
             "offloaded": sum(1 for r in done if r.route == PRFAAS),
-            "ttft_mean_s": float(np.mean(ttft)) if ttft else 0.0,
+            **self._latency_means(done),
             "kv_bytes_total": sum(r.kv_bytes for r in done
                                   if r.route == PRFAAS),
             "cache_hit_rate": {k: c.hit_rate()

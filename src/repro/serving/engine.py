@@ -50,6 +50,15 @@ Compile counts are observable (``PrefillEngine.compiles``,
 ``DecodeEngine.block_compiles``) so benchmarks and tests can assert the
 zero-recompile property instead of trusting it.
 
+Every phase runs inside a ``serving.spans`` span (``prfaas.tick``,
+``prfaas.admit``, ``prfaas.prefill.unit/chunk/finish``, and the decode
+block as ``prfaas.decode.block``, split into ``prfaas.decode.dispatch``
+-> ``.sync`` -> ``.bookkeep``); the walls above are read from those
+spans.  Each ``Request`` is stamped on the same clock where its events
+happen: ``t_submit`` (``submit``), ``t_unit_start`` (its prefill unit
+formed), ``t_first`` (unit done), ``t_admit`` (``admit_many``) and
+``t_finish`` (retired).
+
 **Paged KV (``DecodeEngine(..., paged=True)``)** replaces the dense
 per-slot buffers with the ``core.blockpool.BlockPool`` as the real device
 cache layout (``models/paged.py``):
@@ -90,6 +99,7 @@ from repro.models import Model, prepare_decode_caches
 from repro.models import paged as paged_mod
 from repro.models.kvcache import cache_num_bytes, quantize_cache_for_wire
 from repro.serving.api import Request, Response
+from repro.serving.spans import span
 
 _SEQ_LEAVES = ("k", "v", "ckv", "kpe")
 
@@ -252,22 +262,23 @@ class PrefillEngine:
         ``trim_request_cache(caches, i, length)`` before shipping so wire
         bytes reflect the prompt, not the bucket.
         """
-        t0 = time.perf_counter()
-        toks, lens, B, chunked = self._pad(tokens, lengths)
-        self.calls += 1
-        if chunked:
-            cp = ChunkedPrefill(self, toks, lens, B)
-            while not cp.done:
-                cp.step()
-            first, caches = cp.finish()
-        else:
-            Bb, Sb = toks.shape
-            self._shape_keys.add(("prefill", Bb, Sb))
-            first, caches = self._prefill(self.params, jnp.asarray(toks),
-                                          jnp.asarray(lens))
-            self.tokens_prefilled += int(lens[:B].sum())
-        jax.block_until_ready(first)
-        return np.asarray(first)[:B], caches, time.perf_counter() - t0
+        with span("prfaas.prefill.unit") as sp:
+            toks, lens, B, chunked = self._pad(tokens, lengths)
+            self.calls += 1
+            if chunked:
+                cp = ChunkedPrefill(self, toks, lens, B)
+                while not cp.done:
+                    cp.step()
+                first, caches = cp.finish()
+            else:
+                Bb, Sb = toks.shape
+                self._shape_keys.add(("prefill", Bb, Sb))
+                first, caches = self._prefill(self.params, jnp.asarray(toks),
+                                              jnp.asarray(lens))
+                self.tokens_prefilled += int(lens[:B].sum())
+            jax.block_until_ready(first)
+            first = np.asarray(first)[:B]
+        return first, caches, sp.seconds
 
     def start_chunked(self, tokens: np.ndarray, lengths=None
                       ) -> "ChunkedPrefill":
@@ -349,30 +360,30 @@ class ChunkedPrefill:
 
     def step(self) -> bool:
         """Advance one chunk; returns True once all chunks have run."""
-        t0 = time.perf_counter()
         eng, C, i = self.eng, self.C, self.i
-        Bb = self.toks.shape[0]
-        eng._shape_keys.add(("chunk", Bb, C, i, self.off)
-                            + (("paged",) if self.table_direct else ()))
-        pos = np.broadcast_to(
-            np.arange(self.off + i * C, self.off + (i + 1) * C,
-                      dtype=np.int32)[None], (Bb, C))
-        chunk_lens = np.clip(self.lens - i * C, 0, C).astype(np.int32)
-        h, self.caches = eng._chunk(
-            eng.params,
-            {"tokens": jnp.asarray(self.toks[:, i * C:(i + 1) * C]),
-             "positions": jnp.asarray(pos),
-             "lengths": jnp.asarray(chunk_lens)},
-            self.caches)
-        if self._last is None:
-            self._last = jnp.zeros((Bb, 1, h.shape[-1]), h.dtype)
-        self._last = eng._carry_last(h, self._last, self._lens_dev,
-                                     jnp.int32(i * C))
-        eng._shape_keys.add(("carry", Bb, C))
-        self.i += 1
-        if self.done:
-            jax.block_until_ready(self._last)
-        self.wall_s += time.perf_counter() - t0
+        with span("prfaas.prefill.chunk") as sp:
+            Bb = self.toks.shape[0]
+            eng._shape_keys.add(("chunk", Bb, C, i, self.off)
+                                + (("paged",) if self.table_direct else ()))
+            pos = np.broadcast_to(
+                np.arange(self.off + i * C, self.off + (i + 1) * C,
+                          dtype=np.int32)[None], (Bb, C))
+            chunk_lens = np.clip(self.lens - i * C, 0, C).astype(np.int32)
+            h, self.caches = eng._chunk(
+                eng.params,
+                {"tokens": jnp.asarray(self.toks[:, i * C:(i + 1) * C]),
+                 "positions": jnp.asarray(pos),
+                 "lengths": jnp.asarray(chunk_lens)},
+                self.caches)
+            if self._last is None:
+                self._last = jnp.zeros((Bb, 1, h.shape[-1]), h.dtype)
+            self._last = eng._carry_last(h, self._last, self._lens_dev,
+                                         jnp.int32(i * C))
+            eng._shape_keys.add(("carry", Bb, C))
+            self.i += 1
+            if self.done:
+                jax.block_until_ready(self._last)
+        self.wall_s += sp.seconds
         return self.done
 
     def finish(self):
@@ -381,14 +392,14 @@ class ChunkedPrefill:
         if not self.done:
             raise RuntimeError(f"chunked prefill at chunk {self.i}"
                                f"/{self.n_chunks}; not finished")
-        t0 = time.perf_counter()
-        Bb = self.toks.shape[0]
-        self.eng._shape_keys.add(("finish", Bb))
-        first = self.eng._finish(self.eng.params, self._last,
-                                 jnp.ones((Bb,), jnp.int32))
-        jax.block_until_ready(first)
-        self.eng.tokens_prefilled += int(self.lens[:self.n_valid].sum())
-        self.wall_s += time.perf_counter() - t0
+        with span("prfaas.prefill.finish") as sp:
+            Bb = self.toks.shape[0]
+            self.eng._shape_keys.add(("finish", Bb))
+            first = self.eng._finish(self.eng.params, self._last,
+                                     jnp.ones((Bb,), jnp.int32))
+            jax.block_until_ready(first)
+            self.eng.tokens_prefilled += int(self.lens[:self.n_valid].sum())
+        self.wall_s += sp.seconds
         caches = self.caches
         if self.table_direct:
             # the pool pages/tables were only operands for the chunk steps;
@@ -493,15 +504,15 @@ class DecodeEngine:
             if self.paged:
                 self._block_spec_paged = jax.jit(self._block_spec_paged_impl,
                                                  donate_argnums=(2,))
-        # per-request time-between-tokens: wall seconds per emitted token
-        # after the first, recorded at retirement
-        self._admit_wall: Dict[int, float] = {}
+        # per-request time-between-tokens: wall seconds from the first token
+        # (``Request.t_first``) to retirement per emitted token after it
         self.tbt_s: List[float] = []
         self.lengths = np.zeros((num_slots,), np.int32)
         self.tokens = np.zeros((num_slots,), np.int32)
         self.active = np.zeros((num_slots,), bool)
         self.budget = np.zeros((num_slots,), np.int32)
         self.slot_req: List[Optional[int]] = [None] * num_slots
+        self._slot_request: List[Optional[Request]] = [None] * num_slots
         self.outputs: Dict[int, Response] = {}
         self.truncations = 0
         # occupancy telemetry: wall seconds spent inside step_block, the
@@ -702,6 +713,7 @@ class DecodeEngine:
                 snap = ({"ring": payload["ring"], "state": payload["state"]}
                         if L % T == 0 else None)
                 self.on_admit(req, L, seq_all, snap)
+        self._stamp_admitted(t[0] for t in taken)
         return n
 
     def _ensure_pages(self):
@@ -830,19 +842,26 @@ class DecodeEngine:
             self.slot_req[slot] = req.rid
             self.outputs[req.rid] = Response(req.rid, [int(first_token)])
             self._seed_slot_history(slot, req, first_token, prompt_len)
+        self._stamp_admitted(req for req, *_ in take)
         return n
+
+    @staticmethod
+    def _stamp_admitted(reqs):
+        t = time.perf_counter()
+        for req in reqs:
+            req.t_admit = t
 
     def _seed_slot_history(self, slot: int, req: Request, first_token: int,
                            prompt_len: int):
-        """Drafter history (prompt + first token) and TBT admission stamp."""
+        """The slot's request, and the drafter history (prompt + first
+        token)."""
+        self._slot_request[slot] = req
         if self.spec_k:
             self._hist[slot, :] = 0
             L = min(prompt_len, self._hist.shape[1])
             self._hist[slot, :L] = np.asarray(req.tokens[:L], np.int32)
             if prompt_len < self._hist.shape[1]:
                 self._hist[slot, prompt_len] = first_token
-        if not self._warming:
-            self._admit_wall[req.rid] = time.perf_counter()
 
     # ----------------------------------------------------------------- step
     def _retire(self, slot: int, force_truncate: bool = False):
@@ -855,13 +874,14 @@ class DecodeEngine:
                                        and self.budget[slot] > 0)
         resp.truncated = bool(truncated)
         self.truncations += int(truncated)
-        t_admit = self._admit_wall.pop(rid, None)
-        if t_admit is not None and not self._warming:
-            n_tok = len(resp.output_tokens)
-            self.tbt_s.append((time.perf_counter() - t_admit)
-                              / max(1, n_tok - 1))
+        req = self._slot_request[slot]
+        req.t_finish = time.perf_counter()
+        if req.t_first is not None and not self._warming:
+            self.tbt_s.append((req.t_finish - req.t_first)
+                              / max(1, len(resp.output_tokens) - 1))
         self.active[slot] = False
         self.slot_req[slot] = None
+        self._slot_request[slot] = None
         self._free.append(slot)
         if self.paged:
             # drop the prefix pins and this slot's own pages: registered
@@ -1049,54 +1069,65 @@ class DecodeEngine:
         Inactive slots decode garbage into their (about-to-be-overwritten)
         cache region; streams that hit their budget or the capacity wall
         mid-block have the surplus tokens discarded on the host — identical
-        retirement semantics to ``step()``."""
+        retirement semantics to ``step()``.
+
+        The block runs in a ``prfaas.decode.block`` span; its phases in
+        ``prfaas.decode.dispatch`` (host-to-device copies and the block
+        call), ``.sync`` (tokens back on the host) and ``.bookkeep``
+        (tokens, budgets, retirement)."""
         if not self.active.any():
             return 0
-        if self.paged:
-            self._ensure_pages()          # may retire page-starved slots
-            if not self.active.any():
-                return 0
-        if self.spec_k:
-            return self._step_block_spec()
-        t0 = time.perf_counter()
-        key = self._key
-        step0 = jnp.int32(self._steps)
-        self._blocks += 1
-        self._steps += self.block_size
-        if self.paged:
-            tables = {"seq": jnp.asarray(self.table_seq),
-                      "ring": jnp.asarray(self.table_ring)}
-            toks, self.caches = self._block_paged(
-                self.params, jnp.asarray(self.tokens),
-                self.caches, jnp.asarray(self.lengths), key, step0, tables)
-        else:
-            toks, self.caches = self._block(
-                self.params, jnp.asarray(self.tokens),
-                self.caches, jnp.asarray(self.lengths), key, step0)
-        toks = np.asarray(toks)                       # (block, num_slots)
-        idx = np.where(self.active)[0]
-        wall = time.perf_counter() - t0
-        self.decode_wall_s += wall
-        self.slot_busy_s += len(idx) * wall
-        # tokens a slot emits before retiring, exactly as step() would:
-        # min(budget, room to capacity-1) per block — floored at 1 because
-        # step() appends once BEFORE its retirement check, so a slot
-        # admitted at/over the capacity wall still emits one token
-        valid = np.clip(
-            np.minimum(self.budget[idx],
-                       self.capacity - 1 - self.lengths[idx]),
-            1, self.block_size).astype(int)
-        self.tokens_out += int(valid.sum())
-        self.lengths[idx] += valid
-        self.budget[idx] -= valid
-        self.tokens[idx] = toks[valid - 1, idx]
-        done = (self.budget[idx] <= 0) | \
-               (self.lengths[idx] >= self.capacity - 1)
-        for j, i in enumerate(idx):
-            out = self.outputs[self.slot_req[i]].output_tokens
-            out.extend(int(t) for t in toks[:valid[j], i])
-            if done[j]:
-                self._retire(i)
+        with span("prfaas.decode.block"):
+            if self.paged:
+                self._ensure_pages()      # may retire page-starved slots
+                if not self.active.any():
+                    return 0
+            if self.spec_k:
+                return self._step_block_spec()
+            return self._step_block_plain()
+
+    def _step_block_plain(self):
+        with span("prfaas.decode.dispatch") as dispatch:
+            key = self._key
+            step0 = jnp.int32(self._steps)
+            self._blocks += 1
+            self._steps += self.block_size
+            if self.paged:
+                tables = {"seq": jnp.asarray(self.table_seq),
+                          "ring": jnp.asarray(self.table_ring)}
+                toks, self.caches = self._block_paged(
+                    self.params, jnp.asarray(self.tokens), self.caches,
+                    jnp.asarray(self.lengths), key, step0, tables)
+            else:
+                toks, self.caches = self._block(
+                    self.params, jnp.asarray(self.tokens),
+                    self.caches, jnp.asarray(self.lengths), key, step0)
+        with span("prfaas.decode.sync") as sync:
+            toks = np.asarray(toks)                   # (block, num_slots)
+        with span("prfaas.decode.bookkeep"):
+            idx = np.where(self.active)[0]
+            wall = sync.t1 - dispatch.t0
+            self.decode_wall_s += wall
+            self.slot_busy_s += len(idx) * wall
+            # tokens a slot emits before retiring, exactly as step() would:
+            # min(budget, room to capacity-1) per block — floored at 1
+            # because step() appends once BEFORE its retirement check, so a
+            # slot admitted at/over the capacity wall still emits one token
+            valid = np.clip(
+                np.minimum(self.budget[idx],
+                           self.capacity - 1 - self.lengths[idx]),
+                1, self.block_size).astype(int)
+            self.tokens_out += int(valid.sum())
+            self.lengths[idx] += valid
+            self.budget[idx] -= valid
+            self.tokens[idx] = toks[valid - 1, idx]
+            done = (self.budget[idx] <= 0) | \
+                   (self.lengths[idx] >= self.capacity - 1)
+            for j, i in enumerate(idx):
+                out = self.outputs[self.slot_req[i]].output_tokens
+                out.extend(int(t) for t in toks[:valid[j], i])
+                if done[j]:
+                    self._retire(i)
         return int(self.active.sum())
 
     def _step_block_spec(self):
@@ -1107,46 +1138,49 @@ class DecodeEngine:
         wall lands mid-stream takes only its valid prefix and retires, so
         the device-side history/length frontier stays authoritative exactly
         for the slots that continue."""
-        t0 = time.perf_counter()
-        self._blocks += 1
-        toks = jnp.asarray(self.tokens)
-        lens = jnp.asarray(self.lengths)
-        hist = jnp.asarray(self._hist)
-        if self.paged:
-            tables = {"seq": jnp.asarray(self.table_seq),
-                      "ring": jnp.asarray(self.table_ring)}
-            preds, accepts, self.caches = self._block_spec_paged(
-                self.params, toks, self.caches, lens, hist, tables)
-        else:
-            preds, accepts, self.caches = self._block_spec(
-                self.params, toks, self.caches, lens, hist)
-        preds = np.asarray(preds)        # (rounds, num_slots, spec_k + 1)
-        accepts = np.asarray(accepts)    # (rounds, num_slots)
-        idx = np.where(self.active)[0]
-        wall = time.perf_counter() - t0
-        self.decode_wall_s += wall
-        self.slot_busy_s += len(idx) * wall
-        self.verify_rounds += int(accepts[:, idx].size)
-        self.accepted_tokens += int((accepts[:, idx] + 1).sum())
-        for i in idx:
-            stream = np.concatenate(
-                [preds[r, i, :accepts[r, i] + 1]
-                 for r in range(preds.shape[0])])
-            valid = int(np.clip(
-                min(self.budget[i], self.capacity - 1 - self.lengths[i]),
-                1, len(stream)))
-            take = stream[:valid]
-            self.outputs[self.slot_req[i]].output_tokens.extend(
-                int(t) for t in take)
-            L = int(self.lengths[i])
-            hi = min(L + 1 + valid, self._hist.shape[1])
-            self._hist[i, L + 1:hi] = take[:max(0, hi - (L + 1))]
-            self.tokens[i] = take[-1]
-            self.lengths[i] += valid
-            self.budget[i] -= valid
-            self.tokens_out += valid
-            if self.budget[i] <= 0 or self.lengths[i] >= self.capacity - 1:
-                self._retire(int(i))
+        with span("prfaas.decode.dispatch") as dispatch:
+            self._blocks += 1
+            toks = jnp.asarray(self.tokens)
+            lens = jnp.asarray(self.lengths)
+            hist = jnp.asarray(self._hist)
+            if self.paged:
+                tables = {"seq": jnp.asarray(self.table_seq),
+                          "ring": jnp.asarray(self.table_ring)}
+                preds, accepts, self.caches = self._block_spec_paged(
+                    self.params, toks, self.caches, lens, hist, tables)
+            else:
+                preds, accepts, self.caches = self._block_spec(
+                    self.params, toks, self.caches, lens, hist)
+        with span("prfaas.decode.sync") as sync:
+            preds = np.asarray(preds)    # (rounds, num_slots, spec_k + 1)
+            accepts = np.asarray(accepts)  # (rounds, num_slots)
+        with span("prfaas.decode.bookkeep"):
+            idx = np.where(self.active)[0]
+            wall = sync.t1 - dispatch.t0
+            self.decode_wall_s += wall
+            self.slot_busy_s += len(idx) * wall
+            self.verify_rounds += int(accepts[:, idx].size)
+            self.accepted_tokens += int((accepts[:, idx] + 1).sum())
+            for i in idx:
+                stream = np.concatenate(
+                    [preds[r, i, :accepts[r, i] + 1]
+                     for r in range(preds.shape[0])])
+                valid = int(np.clip(
+                    min(self.budget[i], self.capacity - 1 - self.lengths[i]),
+                    1, len(stream)))
+                take = stream[:valid]
+                self.outputs[self.slot_req[i]].output_tokens.extend(
+                    int(t) for t in take)
+                L = int(self.lengths[i])
+                hi = min(L + 1 + valid, self._hist.shape[1])
+                self._hist[i, L + 1:hi] = take[:max(0, hi - (L + 1))]
+                self.tokens[i] = take[-1]
+                self.lengths[i] += valid
+                self.budget[i] -= valid
+                self.tokens_out += valid
+                if self.budget[i] <= 0 or \
+                        self.lengths[i] >= self.capacity - 1:
+                    self._retire(int(i))
         return int(self.active.sum())
 
     def warmup_block(self):
@@ -1230,6 +1264,7 @@ class RegionScheduler:
     # ------------------------------------------------------------- intake
     def submit(self, req: Request, engine: Optional[PrefillEngine] = None):
         """Enqueue one routed request (state: queued)."""
+        req.t_submit = time.perf_counter()
         self.queue.append((req, engine if engine is not None
                            else self.prefill))
 
@@ -1243,15 +1278,16 @@ class RegionScheduler:
         """Block boundary: move ready -> decoding, as many as slots allow."""
         if not self.ready:
             return 0
-        n = self.decode.admit_many([e for e, _ in self.ready])
-        for _ in range(n):
-            _, born = self.ready.popleft()
-            self.max_admit_wait = max(self.max_admit_wait,
-                                      self.boundaries - born)
-        # the starvation guard: after a boundary admit, a request may only
-        # remain ready because every slot is occupied
-        if self.ready and self.decode.free_slots():
-            self.starved_boundaries += 1
+        with span("prfaas.admit"):
+            n = self.decode.admit_many([e for e, _ in self.ready])
+            for _ in range(n):
+                _, born = self.ready.popleft()
+                self.max_admit_wait = max(self.max_admit_wait,
+                                          self.boundaries - born)
+            # the starvation guard: after a boundary admit, a request may
+            # only remain ready because every slot is occupied
+            if self.ready and self.decode.free_slots():
+                self.starved_boundaries += 1
         return n
 
     def _finish_unit(self, engine, reqs, lengths, first, caches,
@@ -1264,6 +1300,9 @@ class RegionScheduler:
                         trim_request_cache(caches, i, int(lengths[i])),
                         int(lengths[i]))
                        for i, r in enumerate(reqs)]
+        t = time.perf_counter()
+        for r in reqs:
+            r.t_first = t
         for e in entries:
             self.ready.append((e, self.boundaries))
 
@@ -1288,6 +1327,7 @@ class RegionScheduler:
             # device prefix hit: prefill only the uncached suffix, reading
             # the cached prefix straight out of the pinned pool pages
             self.queue.popleft()
+            req0.t_unit_start = time.perf_counter()
             dec = self.decode
             prior = paged_mod.build_prior(
                 dec.model.cfg, dec.caches, dec._layout, pin.seq_ids,
@@ -1303,6 +1343,7 @@ class RegionScheduler:
             # long prompt: becomes the chunk-interleaved unit (batch of 1 —
             # one fixed-shape chunk advances per tick, decode keeps running)
             self.queue.popleft()
+            req0.t_unit_start = time.perf_counter()
             lengths = np.array([len(req0.tokens)], np.int32)
             toks = np.asarray(req0.tokens, np.int32)[None, :]
             self._inflight = (e0.start_chunked(toks, lengths), [req0],
@@ -1322,6 +1363,9 @@ class RegionScheduler:
             else:
                 rest.append((r, e))
         self.queue = rest
+        t = time.perf_counter()
+        for r in unit:
+            r.t_unit_start = t
         lengths = np.array([len(r.tokens) for r in unit], np.int32)
         toks = np.zeros((len(unit), int(lengths.max())), np.int32)
         for i, r in enumerate(unit):
@@ -1333,12 +1377,12 @@ class RegionScheduler:
     def tick(self):
         """One scheduler iteration: admit -> one prefill unit -> one decode
         block.  Returns #active decode slots after the block."""
-        t0 = time.perf_counter()
-        self._admit()
-        self._prefill_one()
-        n = self.decode.step_block()
-        self.boundaries += 1
-        self.wall_s += time.perf_counter() - t0
+        with span("prfaas.tick") as sp:
+            self._admit()
+            self._prefill_one()
+            n = self.decode.step_block()
+            self.boundaries += 1
+        self.wall_s += sp.seconds
         return n
 
     def run(self, max_ticks: int = 100_000) -> int:

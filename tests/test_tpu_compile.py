@@ -6,7 +6,8 @@ cannot (block shapes off the (8, 128) tiling, scalar stores to VMEM, too
 much fast memory).  Widths are the published ones of the two models the
 served path targets: zamba2-1.2b (shared MHA attention, Mamba2) and
 kimi-linear-1t (MLA attention, KDA delta rule).  Each test checks that the
-compiled program contains the kernel (``tpu_custom_call``).
+compiled program contains the kernel (``tpu_custom_call``), named in the
+HLO text by its ``pallas_call`` name.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and a test file is imported by
@@ -14,6 +15,7 @@ every test worker.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,10 +91,13 @@ def one_chip():
             compilation_cache.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(kernel, fn, sharding, *shapes):
+    """Compile ``fn`` and check that its program holds the Pallas call,
+    named by ``kernel`` (the ``pallas_call`` name) in the HLO text."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = .*custom_call_target="
+                     r'"tpu_custom_call"', text), kernel
 
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -101,7 +106,7 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 @pytest.mark.parametrize("model", sorted(ATTN))
 def test_flash_attention_compiles(one_chip, model):
     H, Hkv, Dk, Dv = ATTN[model]["prefill"]
-    _compile(flash_attention, one_chip,
+    _compile("flash_attn", flash_attention, one_chip,
              ((1, H, SEQ, Dk), BF16), ((1, Hkv, SEQ, Dk), BF16),
              ((1, Hkv, SEQ, Dv), BF16))
 
@@ -109,16 +114,16 @@ def test_flash_attention_compiles(one_chip, model):
 def test_flash_attention_chunk_compiles(one_chip):
     """A chunked-prefill chunk: queries attend over prior + own keys."""
     H, Hkv, Dk, Dv = ATTN["zamba2"]["prefill"]
-    _compile(functools.partial(flash_attention, q_offset=SEQ), one_chip,
-             ((1, H, SEQ, Dk), BF16), ((1, Hkv, 2 * SEQ, Dk), BF16),
+    _compile("flash_attn", functools.partial(flash_attention, q_offset=SEQ),
+             one_chip, ((1, H, SEQ, Dk), BF16), ((1, Hkv, 2 * SEQ, Dk), BF16),
              ((1, Hkv, 2 * SEQ, Dv), BF16))
 
 
 @pytest.mark.parametrize("model", sorted(ATTN))
 def test_decode_attention_compiles(one_chip, model):
     H, Hkv, Dk, Dv = ATTN[model]["decode"]
-    _compile(decode_attention, one_chip, ((SLOTS, H, Dk), BF16),
-             ((SLOTS, Hkv, CAPACITY, Dk), BF16),
+    _compile("decode_attn", decode_attention, one_chip,
+             ((SLOTS, H, Dk), BF16), ((SLOTS, Hkv, CAPACITY, Dk), BF16),
              ((SLOTS, Hkv, CAPACITY, Dv), BF16), ((SLOTS,), I32))
 
 
@@ -126,9 +131,10 @@ def test_decode_attention_compiles(one_chip, model):
 def test_paged_decode_attention_compiles(one_chip, model):
     H, Hkv, Dk, Dv = ATTN[model]["decode"]
     pool = SLOTS * CAPACITY // PAGE + 1
-    _compile(paged_decode_attention, one_chip, ((SLOTS, H, Dk), BF16),
-        ((Hkv, pool, PAGE, Dk), BF16), ((Hkv, pool, PAGE, Dv), BF16),
-        ((SLOTS, CAPACITY // PAGE), I32), ((SLOTS,), I32))
+    _compile("paged_decode_attn", paged_decode_attention, one_chip,
+             ((SLOTS, H, Dk), BF16),
+             ((Hkv, pool, PAGE, Dk), BF16), ((Hkv, pool, PAGE, Dv), BF16),
+             ((SLOTS, CAPACITY // PAGE), I32), ((SLOTS,), I32))
 
 
 def test_paged_prefill_attention_compiles(one_chip):
@@ -136,7 +142,8 @@ def test_paged_prefill_attention_compiles(one_chip):
     H, Hkv, D, _ = ATTN["zamba2"]["prefill"]
     pool = SLOTS * CAPACITY // PAGE + 1
     C = 128
-    _compile(paged_prefill_attention, one_chip, ((1, H, C, D), BF16),
+    _compile("paged_prefill_attn", paged_prefill_attention, one_chip,
+             ((1, H, C, D), BF16),
              ((Hkv, pool, PAGE, D), BF16), ((Hkv, pool, PAGE, D), BF16),
              ((1, SEQ // PAGE), I32),
              ((1, Hkv, C, D), BF16), ((1, Hkv, C, D), BF16))
@@ -145,7 +152,7 @@ def test_paged_prefill_attention_compiles(one_chip):
 def test_gla_fused_compiles(one_chip):
     """zamba2's Mamba2 mixer: the length-masked chunked GLA kernel."""
     B, m = 2, ZAMBA_MAMBA
-    _compile(gla_chunked_fused, one_chip,
+    _compile("gla", gla_chunked_fused, one_chip,
              ((B, m.heads, SEQ, m.key_dim), BF16),
              ((B, m.heads, SEQ, m.key_dim), BF16),
              ((B, m.heads, SEQ, m.value_dim), BF16),
@@ -156,7 +163,7 @@ def test_gla_fused_compiles(one_chip):
 def test_delta_fused_compiles(one_chip):
     """kimi-linear-1t's KDA mixer: the length-masked chunked delta rule."""
     B, m = 1, KIMI_KDA
-    _compile(delta_chunked_fused, one_chip,
+    _compile("delta", delta_chunked_fused, one_chip,
              ((B, m.heads, SEQ, m.key_dim), BF16),
              ((B, m.heads, SEQ, m.key_dim), BF16),
              ((B, m.heads, SEQ, m.value_dim), BF16),
@@ -168,5 +175,5 @@ def test_quantize_compiles(one_chip):
     """The int8 wire encode of one K leaf of a 3.5K-token zamba2 prompt
     (6 shared-attention invocations), upcast to f32 as the wire path does."""
     _, Hkv, D, _ = ATTN["zamba2"]["prefill"]
-    _compile(quantize_int8_fused, one_chip,
+    _compile("quantize", quantize_int8_fused, one_chip,
              ((6, 1, 3500, Hkv, D), F32))
