@@ -276,13 +276,13 @@ def delta(q, k, v, log_a, beta, initial_state=None, *, lengths=None,
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, window=0, scale=None,
-                     block_k=512, use_kernel=True):
+                     use_kernel=True):
     if not use_kernel or _on_cpu_lowering(k_cache.shape[2]):
         return ref.decode_attention_ref(q, k_cache, v_cache, lengths,
                                         window=window, scale=scale)
     return _decode.decode_attention(q, k_cache, v_cache, lengths,
                                     window=window, scale=scale,
-                                    block_k=block_k, interpret=_on_cpu())
+                                    interpret=_on_cpu())
 
 
 def verify_attention(q, k_cache, v_cache, lengths, *, scale=None,

@@ -496,6 +496,11 @@ class CrossDCDeployment:
                 "max_admit_wait": self.schedulers[name].max_admit_wait,
                 "accepted_tokens_per_dispatch":
                     dec.accepted_tokens_per_dispatch,
+                # share of the KV capacity the dense decode-attention
+                # kernel copied from HBM (None: no dense block ran)
+                "decode_kv_fetch_share": (
+                    dec.kv_tiles_fetched / dec.kv_tiles_capacity
+                    if dec.kv_tiles_capacity else None),
                 **self._tbt_stats(dec.tbt_s, self.cfg.tbt_slo_s),
             }
             if self.cfg.paged_kv:

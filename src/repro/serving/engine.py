@@ -44,7 +44,10 @@ deterministic per-block key; the default ``temperature=0`` takes the
 argmax through the identical program and stays bit-identical to the
 pre-sampling engine.  The engine also integrates slot-occupancy telemetry
 (``slot_busy_s`` / ``decode_wall_s`` / ``tokens_out``) so schedulers and
-benchmarks can report decode-slot occupancy and goodput.
+benchmarks can report decode-slot occupancy and goodput.  A dense block
+also counts the KV tiles its decode-attention calls copy from HBM
+(``kv_tiles_fetched``) against the tiles of the whole capacity
+(``kv_tiles_capacity``), from the slot lengths on the host.
 
 Compile counts are observable (``PrefillEngine.compiles``,
 ``DecodeEngine.block_compiles``) so benchmarks and tests can assert the
@@ -94,7 +97,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs.base import AttentionSpec
 from repro.core.blockpool import PREFIX, BlockPool
+from repro.kernels.decode_attn import tiles_fetched
 from repro.models import Model, prepare_decode_caches
 from repro.models import paged as paged_mod
 from repro.models.kvcache import cache_num_bytes, quantize_cache_for_wire
@@ -409,6 +414,26 @@ class ChunkedPrefill:
         return np.asarray(first)[:self.n_valid], caches
 
 
+def _attention_calls(cfg, capacity: int):
+    """``[((S, kv_heads, dk, dv, itemsize), layers)]``: the dense
+    decode-attention calls of one decode step, by cache geometry (MLA
+    attends over its latent as one KV head; cross-attention is left out)."""
+    itemsize = 2 if cfg.dtype == "bfloat16" else 4
+    calls: Dict[tuple, int] = {}
+    for *_, b in cfg.iter_blocks():
+        m = b.mixer
+        if not isinstance(m, AttentionSpec):
+            continue
+        if m.kind == "mla":
+            geo = (capacity, 1, m.mla_kv_rank + m.mla_rope_dim,
+                   m.mla_kv_rank, itemsize)
+        else:
+            geo = (m.kv_cache_tokens(capacity), m.kv_heads, m.head_dim,
+                   m.head_dim, itemsize)
+        calls[geo] = calls.get(geo, 0) + 1
+    return list(calls.items())
+
+
 class DecodeEngine:
     """Slot-based continuous batching decode cluster (see module doc)."""
 
@@ -522,6 +547,13 @@ class DecodeEngine:
         self.decode_wall_s = 0.0
         self.slot_busy_s = 0.0
         self.tokens_out = 0
+        # dense blocks: KV tiles the decode-attention kernel copies, and
+        # the tiles of the whole capacity, summed over attention layers
+        self.kv_tiles_fetched = 0
+        self.kv_tiles_capacity = 0
+        self._attn_calls = _attention_calls(model.cfg, capacity)
+        self._block_steps = np.arange(1, self.block_size + 1,
+                                      dtype=np.int32)[:, None]
         self._free = deque(range(num_slots))
         self._step = jax.jit(model.decode_step, donate_argnums=(2,))
         self._block = jax.jit(self._block_impl, donate_argnums=(2,))
@@ -1109,6 +1141,8 @@ class DecodeEngine:
             wall = sync.t1 - dispatch.t0
             self.decode_wall_s += wall
             self.slot_busy_s += len(idx) * wall
+            if not self.paged:
+                self._count_kv_tiles()
             # tokens a slot emits before retiring, exactly as step() would:
             # min(budget, room to capacity-1) per block — floored at 1
             # because step() appends once BEFORE its retirement check, so a
@@ -1129,6 +1163,17 @@ class DecodeEngine:
                 if done[j]:
                     self._retire(i)
         return int(self.active.sum())
+
+    def _count_kv_tiles(self):
+        """Add the block's decode-attention tiles to ``kv_tiles_*``: step
+        ``i`` of the scan attends over ``lengths + 1 + i`` keys in every
+        slot, active or not."""
+        lens = self.lengths + self._block_steps
+        for (S, kv_heads, dk, dv, itemsize), n in self._attn_calls:
+            got, cap = tiles_fetched(np.minimum(lens, S), S, kv_heads=kv_heads,
+                                     dk=dk, dv=dv, itemsize=itemsize)
+            self.kv_tiles_fetched += n * got
+            self.kv_tiles_capacity += n * cap
 
     def _step_block_spec(self):
         """Speculative ``step_block``: ``block_size`` draft/verify rounds in

@@ -165,6 +165,27 @@ class TestDecodeBlock:
             assert a.outputs[i].output_tokens == b.outputs[i].output_tokens
             assert b.outputs[i].finished and not b.outputs[i].truncated
 
+    def test_kv_tile_counter(self, danube, monkeypatch):
+        """A dense block adds, for every attention layer and scan step, the
+        KV tiles the decode-attention kernel copies for each slot (step
+        ``i`` attends over ``lengths + i`` keys, at most the ring's 64) and
+        the tiles of the whole capacity.  16-key tiles (a smaller tile
+        budget) give the smoke widths several tiles per slot."""
+        from repro.kernels import decode_attn
+        monkeypatch.setattr(decode_attn, "TILE_BYTES", 2 * 32 * 4 * 16)
+        cfg, model, params = danube
+        eng = DecodeEngine(model, params, 2, 128, block_size=4)
+        _admit_all(eng, cfg, model, params, [16, 40], max_new=20)
+        ((S, *_), layers), = eng._attn_calls
+        assert (S, layers) == (64, 2)
+        eng.step_block()
+        keys = [min(L + i, S) for i in range(1, 5) for L in (16, 40)]
+        assert eng.kv_tiles_fetched == layers * sum(-(-k // 16) for k in keys)
+        assert eng.kv_tiles_capacity == layers * len(keys) * S // 16
+        fetched = eng.kv_tiles_fetched
+        eng.step_block()
+        assert eng.kv_tiles_fetched > fetched
+
     def test_block_compiles_once(self, danube):
         cfg, model, params = danube
         eng = DecodeEngine(model, params, 4, 128, block_size=4)

@@ -143,3 +143,7 @@ def test_request_stamps_follow_the_request_through_the_deployment():
     assert m["link_exposed_s_mean"] == pytest.approx(
         np.mean([r.transfer_s for r in reqs]))
     assert reqs[1].transfer_s > 0 and reqs[0].transfer_s == 0
+    # the dense decode blocks counted the KV tiles their attention copied
+    share = m["clusters"]["pd"]["decode_kv_fetch_share"]
+    assert share == dec.kv_tiles_fetched / dec.kv_tiles_capacity
+    assert 0 < share <= 1

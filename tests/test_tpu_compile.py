@@ -3,9 +3,10 @@
 No chip is needed: the TPU compiler is installed and compiles for a chip
 that is described, not attached, so these tests catch what interpret mode
 cannot (block shapes off the (8, 128) tiling, scalar stores to VMEM, too
-much fast memory).  Widths are the published ones of the two models the
-served path targets: zamba2-1.2b (shared MHA attention, Mamba2) and
-kimi-linear-1t (MLA attention, KDA delta rule).  Each test checks that the
+much fast memory).  Widths are the published ones of the models the
+served path targets: zamba2-1.2b (shared MHA attention, Mamba2),
+kimi-linear-1t (MLA attention, KDA delta rule) and mistral-nemo-12b (GQA,
+the benchmark's model).  Each test checks that the
 compiled program contains the kernel (``tpu_custom_call``), named in the
 HLO text by its ``pallas_call`` name.
 
@@ -43,6 +44,7 @@ def _mixers(arch, kind):
             if isinstance(b.mixer, kind)]
 
 
+MISTRAL_ATTN = _mixers("mistral-nemo-12b", AttentionSpec)[0]
 ZAMBA_ATTN = _mixers("zamba2-1.2b", AttentionSpec)[0]
 ZAMBA_MAMBA = _mixers("zamba2-1.2b", LinearSpec)[0]
 KIMI_MLA = _mixers("kimi-linear-1t", AttentionSpec)[0]
@@ -63,7 +65,8 @@ def _attention_widths(spec):
 
 
 ATTN = {"zamba2": _attention_widths(ZAMBA_ATTN),
-        "kimi-mla": _attention_widths(KIMI_MLA)}
+        "kimi-mla": _attention_widths(KIMI_MLA),
+        "mistral-nemo": _attention_widths(MISTRAL_ATTN)}
 
 
 @pytest.fixture(scope="module")
@@ -95,9 +98,10 @@ def _compile(kernel, fn, sharding, *shapes):
     """Compile ``fn`` and check that its program holds the Pallas call,
     named by ``kernel`` (the ``pallas_call`` name) in the HLO text."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    compiled = jax.jit(fn).lower(*args).compile()
     assert re.search(rf"%{kernel}(\.\d+)? = .*custom_call_target="
-                     r'"tpu_custom_call"', text), kernel
+                     r'"tpu_custom_call"', compiled.as_text()), kernel
+    return compiled
 
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -125,6 +129,38 @@ def test_decode_attention_compiles(one_chip, model):
     _compile("decode_attn", decode_attention, one_chip,
              ((SLOTS, H, Dk), BF16), ((SLOTS, Hkv, CAPACITY, Dk), BF16),
              ((SLOTS, Hkv, CAPACITY, Dv), BF16), ((SLOTS,), I32))
+
+
+def test_decode_attention_signature_is_the_benchmarks(one_chip):
+    """At the benchmark's decode widths (mistral-nemo-12b: 32 q / 8 kv
+    heads of 128, 4 slots of 4,096 keys) the ``decode_attn`` call keeps
+    the operand signature by which the benchmark's trace reduction tells
+    it apart: ``bench/kernels/decode_attn.py`` accepts it and
+    ``bench/kernels/flash_attn.py`` does not."""
+    from jax._src.lib import _jax
+
+    from bench import trace
+    from bench.kernels import decode_attn as bench_decode
+    from bench.kernels import flash_attn as bench_flash
+
+    H, Hkv, Dk, Dv = ATTN["mistral-nemo"]["decode"]
+    slots = 4
+    compiled = _compile("decode_attn", decode_attention, one_chip,
+                        ((slots, H, Dk), BF16),
+                        ((slots, Hkv, CAPACITY, Dk), BF16),
+                        ((slots, Hkv, CAPACITY, Dv), BF16), ((slots,), I32))
+    opts = _jax.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True          # as the profiler names ops
+    opts.print_percent = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    sigs = [trace.signature(line.strip()) for line in text.splitlines()
+            if line.strip().startswith("%decode_attn")]
+    assert sigs == [f"bf16[{slots * H},1,{Dv}] <- s32[{slots}] "
+                    f"bf16[{slots * H},1,{Dk}] "
+                    f"bf16[{slots * Hkv},{CAPACITY},{Dk}] "
+                    f"bf16[{slots * Hkv},{CAPACITY},{Dv}]"]
+    assert bench_decode.match(sigs[0])
+    assert not bench_flash.match(sigs[0])
 
 
 @pytest.mark.parametrize("model", sorted(ATTN))
