@@ -6,18 +6,12 @@ from typing import List, Tuple
 
 
 def layers(config: dict, kind: str) -> List[Tuple[dict, int]]:
-    """(mixer spec, invocations per token) for every block whose mixer is
-    ``attention`` or the linear ``kind`` (e.g. ``mamba2``)."""
-    m = config["model"]
-    out = []
-    for g in m["groups"]:
-        for name in g["blocks"]:
-            b = m["blocks"][name]
-            mixer = b["mixer"]
-            if (kind == "attention" and mixer["type"] == "attention") or \
-                    (mixer["type"] == "linear" and mixer["kind"] == kind):
-                out.append((b, int(g["repeats"])))
-    return out
+    """(block spec, invocations per token) for every block whose mixer is of
+    ``kind`` (e.g. ``mamba2``, ``mla``); ``attention`` names ``full``, the
+    GQA attention that the attention kernels' cost files price."""
+    kind = "full" if kind == "attention" else kind
+    return [(b, reps) for b, reps in blocks(config)
+            if b["mixer"]["kind"] == kind]
 
 
 def blocks(config: dict) -> List[Tuple[dict, int]]:

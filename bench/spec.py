@@ -7,9 +7,11 @@ files behind those names are
     bench/traffic/<traffic>.json     the mix's parameters (bench/traffic.py)
     bench/cells/<cell>.json          the cell's offered rate (req/s)
     bench/metrics/<metric>.py        one reader per per-layer metric
+    bench/layers/<part>.<kind>.py    one mixer or FFN kind's reference
+                                     equations and FLOPs (bench/layers)
 
-so a later change adds a cell, a configuration, a mix or a metric by adding
-files, never by editing one.
+so a later change adds a cell, a configuration, a mix, a metric or a layer
+kind by adding files, never by editing one.
 """
 from __future__ import annotations
 
